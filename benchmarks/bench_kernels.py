@@ -3,7 +3,8 @@
 On CPU the wall times are NOT TPU-representative (interpret mode executes
 the kernel body in Python); the purpose here is (a) correctness at bench
 shapes and (b) the FLOP accounting used by the roofline.  On a TPU runtime
-set REPRO_PALLAS_COMPILE=1 to benchmark the Mosaic-compiled kernels.
+the same calls compile through Mosaic (``repro.kernels.ops`` picks by
+platform).
 """
 
 from __future__ import annotations

@@ -24,7 +24,7 @@ import sys
 import tempfile
 import time
 
-REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (
     bench_breakdown,
@@ -41,6 +41,8 @@ from . import (
     bench_serving,
     roofline,
 )
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 BENCHES = {
     "end_to_end": bench_end_to_end,       # Fig. 8
@@ -213,6 +215,7 @@ def main() -> None:
                          "(default: repo root; --smoke: a temp dir) — CI "
                          "points this at its artifact upload path")
     args = ap.parse_args()
+    enable_compile_cache()
 
     all_rows = []
     if args.only:

@@ -31,9 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was named TPUCompilerParams before jax 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -108,7 +105,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     sm_scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Flash attention over head-major layouts. Returns (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
@@ -160,7 +157,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

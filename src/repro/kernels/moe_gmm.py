@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was named TPUCompilerParams before jax 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _gmm_kernel(
     sizes_ref,  # (E,) int32 in SMEM-like memory (full array)
@@ -77,7 +74,7 @@ def grouped_matmul(
     block_c: int = 128,
     block_f: int = 128,
     block_d: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     E, C, d = x.shape
     _, _, f = w.shape
@@ -106,7 +103,7 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, ic, if_, id_: (e, ic, if_)),
         out_shape=jax.ShapeDtypeStruct((E, nc * bc, nf * bf), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

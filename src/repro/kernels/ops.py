@@ -1,16 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container) so the kernel
-bodies execute in Python on CPU for validation; on a TPU runtime set
-``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False) to compile through
-Mosaic.  ``ShardingConfig.use_pallas`` gates whether the model layers call
+``interpret=None`` (the default) follows the platform: the kernels compile
+through Mosaic when JAX's default backend is a TPU and run in the Pallas
+interpreter everywhere else (CPU tests).  Pass ``interpret=`` to force
+either.  ``ShardingConfig.use_pallas`` gates whether the model layers call
 these instead of the XLA chunked paths.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -21,8 +20,6 @@ from .rglru_scan import rglru_scan as _rglru
 
 
 def _default_interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
     return jax.default_backend() != "tpu"
 
 
@@ -70,7 +67,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
                     interpret: bool | None = None):
     """Paged decode attention: the Mosaic kernel on a TPU runtime; under
-    interpret mode (this container) it falls back to the reference gather —
+    interpret mode (off-TPU) it falls back to the reference gather —
     the exact arithmetic the serving decode path uses — instead of
     interpreting the kernel body token-by-token."""
     interpret = _default_interpret() if interpret is None else interpret

@@ -107,7 +107,7 @@ def paged_attention(
     lengths: jnp.ndarray,  # (B,) int32: positions <= lengths[b] are valid
     *,
     sm_scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Paged decode attention over head-major layouts. Returns (B, H, hd)."""
     B, H, hd = q.shape
@@ -153,10 +153,6 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, rep, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-        if hasattr(pltpu, "CompilerParams")
-        else pltpu.TPUCompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was named TPUCompilerParams before jax 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _scan_kernel(
     a_ref,  # (1, chunk, bd)
@@ -56,7 +53,7 @@ def rglru_scan(
     *,
     chunk: int = 256,
     block_d: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Linear recurrence h_t = a_t·h_{t-1} + b_t, h_0 = b_0 (zero init)."""
     B, S, D = a.shape
@@ -79,7 +76,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, ch, bd), lambda ib, idb, ic: (ib, ic, idb)),
         out_shape=jax.ShapeDtypeStruct((B, ns * ch, ndb * bd), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

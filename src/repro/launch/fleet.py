@@ -42,6 +42,7 @@ from typing import Dict, List, Set
 from ..core.placement import ClusterSpec
 from ..fleet import FleetCallbacks, FleetConfig, FleetScheduler, JobSpec
 from ..launch.events import ScriptedEventSource, StragglerDetected
+from .compile_cache import enable_compile_cache
 
 
 class FleetPrinter(FleetCallbacks):
@@ -279,6 +280,7 @@ def main() -> None:
     ap.add_argument("--revoke-deadline", type=int, default=4,
                     help="revoke-smoke: ticks a holder gets to yield")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.revoke_smoke:
         m = revoke_smoke(

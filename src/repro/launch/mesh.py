@@ -11,14 +11,16 @@ TP/EP/SP on ``model`` accordingly.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         (AxisType.Auto,) * 2)

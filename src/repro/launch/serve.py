@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from ..config import get_arch
 from ..serving import Request, ServingConfig, ServingSession
+from .compile_cache import enable_compile_cache
 
 
 def _build_requests(session: ServingSession, *, n_requests: int,
@@ -271,6 +272,7 @@ def main() -> None:
                          "without sharing; fail unless hits > 0, KV "
                          "high-water shrinks, and tokens match exactly")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.prefix_smoke:
         sys.exit(prefix_smoke(args))
     out = serve(

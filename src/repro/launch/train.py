@@ -43,8 +43,9 @@ from ..config import default_sharding, get_arch, reduced
 from ..ckpt import CheckpointManager, StragglerDetector
 from ..data import DataConfig, SyntheticLM, shard_batch
 from ..models import build_model
-from ..optim import AdamW, warmup_cosine
-from ..parallel import batch_axes, tree_param_specs
+from ..optim import AdamW, OptState, warmup_cosine
+from ..parallel import ShardingRules, batch_axes, tree_param_shardings
+from .compile_cache import enable_compile_cache
 
 
 def plan_preview(
@@ -330,15 +331,22 @@ def crash_smoke(
     }
 
 
-def make_train_state(model, optimizer, rng, mesh=None, rules=None):
+def make_train_state(model, optimizer, rng, rules=None):
+    """Fresh ``(params, opt_state)``.  With ``rules``, parameters and both
+    AdamW moments are placed by :func:`repro.parallel.tree_param_shardings`
+    (FSDP over ``data`` when ``ShardingConfig.fsdp``); the step counter is
+    replicated.  Without, everything stays on the default device."""
     params = model.init(rng)
+    if rules is None:
+        return params, optimizer.init(params)
+    shardings = tree_param_shardings(rules, params)
+    params = jax.device_put(params, shardings)
     opt_state = optimizer.init(params)
-    if mesh is not None and rules is not None:
-        from jax.sharding import NamedSharding
-        p_specs = tree_param_specs(rules, jax.eval_shape(lambda: params))
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, p_specs
-        )
+    opt_state = jax.device_put(
+        opt_state,
+        OptState(mu=shardings, nu=shardings,
+                 count=rules.named(jax.sharding.PartitionSpec())),
+    )
     return params, opt_state
 
 
@@ -351,7 +359,6 @@ def _make_compressed_dp_step(model, optimizer, mesh):
     traffic than fp32 all-reduce, the cross-pod/DCN trick from DESIGN.md
     §8. The optimizer update runs on the synced grads (replicated math)."""
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..optim.compress import compressed_mean
@@ -367,15 +374,15 @@ def _make_compressed_dp_step(model, optimizer, mesh):
         return synced, loss
 
     batch_spec = P("data")
-    grads_fn = shard_map(
+    grads_fn = jax.shard_map(
         local_grads,
         mesh=mesh,
         in_specs=(P(), batch_spec),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
-    @jax.jit
+    @partial(jax.jit, donate_argnums=(0, 1))
     def step_fn(params, opt_state, b):
         grads, loss = grads_fn(params, b)
         new_params, new_state = optimizer.update(grads, opt_state, params)
@@ -434,13 +441,17 @@ def train(
     optimizer = AdamW(
         lr=partial(warmup_cosine, peak_lr=lr, warmup_steps=max(steps // 10, 1),
                    total_steps=steps),
-        moment_dtype=jnp.float32 if reduced_cfg else None or jnp.float32,
+        moment_dtype=jnp.dtype(cfg.opt_dtype),
     )
     data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=seq,
                                   global_batch=batch, seed=seed))
 
+    # the compressed-DP step keeps parameters replicated (pure DP); every
+    # other step under a mesh shards them by the arch's sharding rules
+    rules = (ShardingRules(mesh, shcfg)
+             if mesh is not None and not compress_grads else None)
     params, opt_state = make_train_state(
-        model, optimizer, jax.random.PRNGKey(seed)
+        model, optimizer, jax.random.PRNGKey(seed), rules=rules
     )
 
     start_step = 0
@@ -458,7 +469,9 @@ def train(
     if compress_grads and mesh is not None and "data" in mesh.axis_names:
         step_fn = _make_compressed_dp_step(model, optimizer, mesh)
     else:
-        @jax.jit
+        # params and optimizer state are donated: the update writes in
+        # place, so one copy of the training state is live, not two
+        @partial(jax.jit, donate_argnums=(0, 1))
         def step_fn(params, opt_state, b):
             (loss, metrics), grads = jax.value_and_grad(
                 lambda p: model.loss(p, b, mesh=mesh), has_aux=True
@@ -557,6 +570,7 @@ def main() -> None:
     ap.add_argument("--kill-hosts", default="1",
                     help="crash-smoke: comma-separated host ids to kill")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.crash_smoke:
         crash_smoke(
             steps=args.steps,

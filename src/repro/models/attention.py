@@ -138,7 +138,13 @@ def chunked_attention(
                 if pad_k:
                     mask &= (kpos[None, :] < Sk)
                 s = jnp.where(mask[None, None], s, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                # the running max only keeps exp() in range and the output
+                # does not depend on it, so it takes no gradient.  A max's
+                # gradient divides by its tie count, which is zero (NaN)
+                # when the TPU recomputes s under remat with other rounding.
+                m_new = jax.lax.stop_gradient(
+                    jnp.maximum(m, jnp.max(s, axis=-1))
+                )
                 p = jnp.exp(s - m_new[..., None])
                 alpha = jnp.exp(m - m_new)
                 l_new = l * alpha + jnp.sum(p, axis=-1)

@@ -139,7 +139,6 @@ def moe_apply(
         and m.n_physical % mesh.devices.shape[mesh.axis_names.index(model_axis)] == 0
     ):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         ax = mesh.axis_names.index(model_axis)
         n_shards = mesh.devices.shape[ax]
@@ -155,7 +154,7 @@ def moe_apply(
                 aux = jax.lax.pmean(aux, batch_axes)
             return out, aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             shmap_fn,
             mesh=mesh,
             in_specs=(

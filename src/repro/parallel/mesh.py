@@ -22,8 +22,13 @@ AxisNames = Tuple[str, ...]
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Build a mesh over the available devices (CPU hosts or TPU chips)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Build a mesh over the available devices (CPU hosts or TPU chips).
+
+    Axes are ``Auto``: the sharding rules place arrays and GSPMD propagates
+    the rest (``jax.make_mesh`` would otherwise default to ``Explicit``).
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_over_devices(
@@ -34,14 +39,21 @@ def mesh_over_devices(
     """Build a mesh over an EXPLICIT device-id subset — the elastic re-mesh
     primitive: after a straggler eviction, the session rebuilds its mesh
     from ``ClusterSpec.healthy_devices()`` so restored arrays land only on
-    surviving hosts' devices.  Ids beyond the runtime's device count are
-    dropped (plans are sized for the full cluster; a smaller local runtime
-    keeps a valid prefix).  ``shape`` defaults to 1-D over the survivors.
+    surviving hosts' devices.  An id the runtime does not have raises: a
+    plan sized for a larger cluster must not silently run on fewer devices.
+    ``shape`` defaults to 1-D over the survivors.
     """
     pool = jax.devices()
-    devs = [pool[d] for d in device_ids if d < len(pool)]
-    if not devs:
-        raise ValueError("mesh_over_devices: no addressable devices in subset")
+    ids = list(device_ids)
+    missing = [d for d in ids if not 0 <= d < len(pool)]
+    if missing:
+        raise ValueError(
+            f"mesh_over_devices: device ids {missing} do not exist "
+            f"(runtime has {len(pool)} devices)"
+        )
+    if not ids:
+        raise ValueError("mesh_over_devices: empty device subset")
+    devs = [pool[d] for d in ids]
     arr = np.array(devs)
     if shape is not None:
         arr = arr.reshape(tuple(shape))

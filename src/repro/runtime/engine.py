@@ -55,6 +55,8 @@ class WaveEngine:
     def __init__(self, model: MTModel, plan: ExecutionPlan, *,
                  distributed: bool = False):
         self.model = model
+        #: plan device ids must be real devices (checked on init and rebind)
+        self._check_devices = distributed
         self.distributed = distributed and jax.device_count() > 1
         # Step-closure cache, keyed by plan-id-independent step identity
         # (instance, component, layer range, predecessor roles) — survives
@@ -63,9 +65,29 @@ class WaveEngine:
         # Device-group mesh cache (distributed mode): one Mesh per distinct
         # device tuple, shared by activation and parameter placement.
         self._mesh_cache: Dict[Tuple[int, ...], jax.sharding.Mesh] = {}
+        #: ids of the devices the last ``loss_and_grads`` call's forward
+        #: step outputs landed on (where the plan actually ran)
+        self.output_devices: frozenset = frozenset()
+        self._validate_devices(plan)
         self._bind(plan)
 
     # ------------------------------------------------------------------
+    def _validate_devices(self, plan: ExecutionPlan) -> None:
+        """A distributed engine runs each step on the devices its plan
+        names; a plan sized for a larger cluster is an error, never a
+        silent run on fewer devices."""
+        if not self._check_devices:
+            return
+        n = jax.device_count()
+        missing = sorted(
+            {d for s in plan.steps for d in s.devices if not 0 <= d < n}
+        )
+        if missing:
+            raise ValueError(
+                f"plan places steps on devices {missing}, but the runtime "
+                f"has {n}: plan for the devices that exist"
+            )
+
     def _bind(self, plan: ExecutionPlan) -> None:
         """Derive all plan-dependent lookup structures."""
         self.plan = plan
@@ -104,6 +126,7 @@ class WaveEngine:
         sets reuse their closures instead of rebuilding.
         """
         ref_model = model if model is not None else self.model
+        self._validate_devices(plan)
         if plan.meta_graph is not self.mg or model is not None:
             # validate BEFORE mutating: a raise must leave the engine on
             # its previous (model, plan) pairing, still usable
@@ -145,9 +168,6 @@ class WaveEngine:
         )
         return preds, pred_info
 
-    def _group_devs(self, step: PlanStep) -> Tuple[int, ...]:
-        return tuple(d for d in step.devices if d < jax.device_count())
-
     def _group_mesh(self, devs: Tuple[int, ...]) -> jax.sharding.Mesh:
         mesh = self._mesh_cache.get(devs)
         if mesh is None:
@@ -161,9 +181,7 @@ class WaveEngine:
         """Move an activation onto the step's device group (flow transmission)."""
         if not self.distributed:
             return x
-        devs = self._group_devs(step)
-        if not devs:
-            return x
+        devs = step.devices
         if len(devs) == 1:
             return jax.device_put(x, jax.devices()[devs[0]])
         spec = jax.sharding.PartitionSpec(
@@ -185,9 +203,7 @@ class WaveEngine:
         k-entry instance pays one placement per group, not k."""
         if not self.distributed:
             return p
-        devs = self._group_devs(step)
-        if not devs:
-            return p
+        devs = step.devices
         if len(devs) == 1:
             target = jax.sharding.SingleDeviceSharding(jax.devices()[devs[0]])
         else:
@@ -216,6 +232,7 @@ class WaveEngine:
         # loss_and_grads, so each (instance, device group) pair pays for
         # its replication exactly once per call, not once per wave entry.
         placed: Dict[Tuple[str, Tuple[int, ...]], Any] = {}
+        used: set = set()
 
         waves = self.plan.waves()
         for widx in sorted(waves):
@@ -230,7 +247,7 @@ class WaveEngine:
                     "contrastive", "decoder"
                 )
 
-                pkey = (inst, self._group_devs(step))
+                pkey = (inst, step.devices)
                 inst_p = placed.get(pkey)
                 if inst_p is None:
                     inst_p = self._put_params(params[inst], step)
@@ -253,12 +270,14 @@ class WaveEngine:
                     rec = _StepRecord(step, mid, inst, "mid", [], vjp,
                                       is_loss_step, out_like=out)
                 records.append(rec)
+                used.update(d.id for d in out.devices())
                 if is_loss_step:
                     losses[mid] = out
                 else:
                     acts[mid] = out
             if on_wave is not None:
                 on_wave(widx, waves[widx])
+        self.output_devices = frozenset(used)
 
         n_losses = len(losses)
 
@@ -283,10 +302,7 @@ class WaveEngine:
         def _same_place(y, like):
             if not self.distributed:
                 return y
-            try:
-                return jax.device_put(y, like.sharding)
-            except Exception:  # noqa: BLE001 — fall back to default device
-                return jax.device_put(y, jax.devices()[0])
+            return jax.device_put(y, like.sharding)
 
         for rec in reversed(records):
             mid = rec.meta_id

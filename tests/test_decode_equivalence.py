@@ -101,3 +101,30 @@ def test_attention_impls_agree():
     qm, km, vm = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     a_flash = flash_ops(qm, km, vm, causal=True, block_q=32, block_k=32)
     assert float(jnp.max(jnp.abs(a_flash.transpose(0, 2, 1, 3) - a_naive))) < 2e-5
+
+
+def test_chunked_attention_gradient_skips_the_running_max():
+    """The chunked path's gradient equals the naive one and never flows
+    through the online-softmax max: a max's gradient divides by how many
+    scores tie with it, and a TPU recompute under remat can round the
+    scores so that none does (NaN)."""
+    from repro.models.attention import chunked_attention, naive_attention
+
+    B, H, S, hd = 2, 4, 96, 32
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v = (jax.random.normal(kk, (B, S, H, hd)) for kk in ks[:3])
+    g = jax.random.normal(ks[3], (B, S, H, hd))
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, **kw) * g)
+
+    chunk = jax.jit(jax.grad(loss(chunked_attention, q_chunk=32, kv_chunk=32),
+                             argnums=(0, 1, 2)))
+    want = jax.grad(loss(naive_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(chunk(q, k, v), want):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+    # no score block is compared for equality with its max in the backward
+    block = f"tensor<{B}x{H}x32x32xf32>"
+    ties = [line for line in chunk.lower(q, k, v).as_text().splitlines()
+            if "compare  EQ" in line and block in line]
+    assert not ties, ties
