@@ -14,6 +14,7 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -51,8 +52,9 @@ class AdamW:
         count = state.count + 1
         scale = jnp.asarray(1.0, jnp.float32)
         if self.grad_clip > 0:
-            gnorm = global_norm(grads)  # scalar; per-leaf fused reductions
-            scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
+            with TraceAnnotation("spindle.optim.clip"):
+                gnorm = global_norm(grads)  # scalar; per-leaf fused reductions
+                scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
 
         b1, b2 = self.b1, self.b2
         c = count.astype(jnp.float32)

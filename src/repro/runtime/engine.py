@@ -34,6 +34,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.plan import ExecutionPlan, PlanStep
 from .mtmodel import ExecComponent, MTModel
@@ -239,42 +240,43 @@ class WaveEngine:
             for step in waves[widx]:
                 mid = step.meta_id
                 inst, comp, task = self.meta_info[mid]
-                c = model.components[comp]
-                lo, hi = self._layer_range(step)
-                m = self.mg.meta_ops[mid]
-                terminal = not self._succs[mid]
-                is_loss_step = terminal and hi == m.L and c.kind in (
-                    "contrastive", "decoder"
-                )
+                with TraceAnnotation(f"spindle.fwd:{inst}"):
+                    c = model.components[comp]
+                    lo, hi = self._layer_range(step)
+                    m = self.mg.meta_ops[mid]
+                    terminal = not self._succs[mid]
+                    is_loss_step = terminal and hi == m.L and c.kind in (
+                        "contrastive", "decoder"
+                    )
 
-                pkey = (inst, step.devices)
-                inst_p = placed.get(pkey)
-                if inst_p is None:
-                    inst_p = self._put_params(params[inst], step)
-                    placed[pkey] = inst_p
-                if lo == 0:
-                    preds, pred_info = self._entry_preds(mid)
-                    pred_acts = [self._put(acts[p], step) for p in preds]
-                    fn = self._make_entry_fn(
-                        c, inst, pred_info, lo, hi, is_loss_step, task
-                    )
-                    out, vjp = jax.vjp(
-                        partial(fn, batches), inst_p, *pred_acts
-                    )
-                    rec = _StepRecord(step, mid, inst, "entry", preds, vjp,
-                                      is_loss_step, out_like=out)
-                else:
-                    h_in = self._put(acts[mid], step)
-                    fn = self._make_mid_fn(c, inst, lo, hi, is_loss_step, task)
-                    out, vjp = jax.vjp(partial(fn, batches), inst_p, h_in)
-                    rec = _StepRecord(step, mid, inst, "mid", [], vjp,
-                                      is_loss_step, out_like=out)
-                records.append(rec)
-                used.update(d.id for d in out.devices())
-                if is_loss_step:
-                    losses[mid] = out
-                else:
-                    acts[mid] = out
+                    pkey = (inst, step.devices)
+                    inst_p = placed.get(pkey)
+                    if inst_p is None:
+                        inst_p = self._put_params(params[inst], step)
+                        placed[pkey] = inst_p
+                    if lo == 0:
+                        preds, pred_info = self._entry_preds(mid)
+                        pred_acts = [self._put(acts[p], step) for p in preds]
+                        fn = self._make_entry_fn(
+                            c, inst, pred_info, lo, hi, is_loss_step, task
+                        )
+                        out, vjp = jax.vjp(
+                            partial(fn, batches), inst_p, *pred_acts
+                        )
+                        rec = _StepRecord(step, mid, inst, "entry", preds, vjp,
+                                          is_loss_step, out_like=out)
+                    else:
+                        h_in = self._put(acts[mid], step)
+                        fn = self._make_mid_fn(c, inst, lo, hi, is_loss_step, task)
+                        out, vjp = jax.vjp(partial(fn, batches), inst_p, h_in)
+                        rec = _StepRecord(step, mid, inst, "mid", [], vjp,
+                                          is_loss_step, out_like=out)
+                    records.append(rec)
+                    used.update(d.id for d in out.devices())
+                    if is_loss_step:
+                        losses[mid] = out
+                    else:
+                        acts[mid] = out
             if on_wave is not None:
                 on_wave(widx, waves[widx])
         self.output_devices = frozenset(used)
@@ -293,7 +295,8 @@ class WaveEngine:
         total = sum(_local(l) for l in losses.values()) / n_losses
 
         # ---------------- backward: reverse wave order ----------------
-        grads = {k: jax.tree.map(jnp.zeros_like, v) for k, v in params.items()}
+        with TraceAnnotation("spindle.grad_init"):
+            grads = {k: jax.tree.map(jnp.zeros_like, v) for k, v in params.items()}
         cot: Dict[int, Any] = {}
 
         def _acc(a, b):
@@ -306,25 +309,27 @@ class WaveEngine:
 
         for rec in reversed(records):
             mid = rec.meta_id
-            if rec.is_loss:
-                g_out = jnp.asarray(1.0 / n_losses, jnp.float32)
-            else:
-                if mid not in cot:
-                    continue  # activation never used (defensive)
-                g_out = cot.pop(mid)
-            if self.distributed:
-                g_out = jax.tree.map(
-                    lambda g, o: _same_place(g, o), g_out, rec.out_like
-                ) if rec.out_like is not None else g_out
-            pulls = rec.vjp_fn(g_out)
+            if not rec.is_loss and mid not in cot:
+                continue  # activation never used (defensive)
+            with TraceAnnotation(f"spindle.bwd:{rec.inst}"):
+                if rec.is_loss:
+                    g_out = jnp.asarray(1.0 / n_losses, jnp.float32)
+                else:
+                    g_out = cot.pop(mid)
+                if self.distributed:
+                    g_out = jax.tree.map(
+                        lambda g, o: _same_place(g, o), g_out, rec.out_like
+                    ) if rec.out_like is not None else g_out
+                pulls = rec.vjp_fn(g_out)
             d_params, d_ins = pulls[0], pulls[1:]
-            grads[rec.inst] = _acc(grads[rec.inst], d_params)
-            if rec.kind == "mid":
-                (d_h,) = d_ins
-                cot[mid] = _acc(cot[mid], d_h) if mid in cot else d_h
-            else:
-                for p, d in zip(rec.pred_order, d_ins):
-                    cot[p] = _acc(cot[p], d) if p in cot else d
+            with TraceAnnotation(f"spindle.grad_acc:{rec.inst}"):
+                grads[rec.inst] = _acc(grads[rec.inst], d_params)
+                if rec.kind == "mid":
+                    (d_h,) = d_ins
+                    cot[mid] = _acc(cot[mid], d_h) if mid in cot else d_h
+                else:
+                    for p, d in zip(rec.pred_order, d_ins):
+                        cot[p] = _acc(cot[p], d) if p in cot else d
         return total, grads
 
     # ------------------------------------------------------------------
@@ -413,5 +418,6 @@ class WaveEngine:
                    on_wave=None):
         """One full §3.6 iteration: fwd+bwd wave-by-wave, group sync, update."""
         loss, grads = self.loss_and_grads(params, batches, on_wave=on_wave)
-        new_params, new_state = optimizer.update(grads, opt_state, params)
+        with TraceAnnotation("spindle.optim"):
+            new_params, new_state = optimizer.update(grads, opt_state, params)
         return new_params, new_state, loss

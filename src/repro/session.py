@@ -51,6 +51,9 @@ from typing import (
     Union,
 )
 
+import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from .core.costmodel import HardwareSpec, V5E
 from .core.estimator import TimeFn
 from .core.graph import TaskGraph
@@ -151,6 +154,10 @@ class SessionCallbacks:
 
     def on_step_end(self, session: "SpindleSession", step: int,
                     loss: float, dt: float) -> None:
+        """``dt`` is host seconds from the step's dispatch to its loss
+        read. The step blocks only on the loss, so the backward pass and
+        the optimizer update may still be running on the device: ``dt``
+        is not the device's step time."""
         pass
 
 
@@ -493,39 +500,47 @@ class SpindleSession:
         Fires ``on_wave`` per forward wave and ``on_step_end`` after the
         update, then drains every event source — a straggler or workload
         shift detected at step *t* replans before step *t+1* begins.
+
+        The step time handed to the event sources and to ``on_step_end``
+        is host time from dispatch to the loss read: the step blocks only
+        on the loss, so the backward pass and the update may still be
+        running on the device when it is taken.
+
+        The step runs inside a ``spindle.step`` profiler span, and the
+        loss read inside ``spindle.loss_read``.
         """
         if self.engine is None:
             raise RuntimeError("bind() a model before calling step()")
-        b = batches if batches is not None else self._step_batches()
-        t0 = time.perf_counter()
-        self.params, self.opt_state, loss = self.engine.train_step(
-            self.params, self.opt_state, b, self.optimizer,
-            on_wave=self._fire_wave,
-        )
-        loss = float(loss)
-        dt = time.perf_counter() - t0
-        self.history.append(loss)
-        step_idx = self.step_count
-        self.step_count += 1
-        if self.event_sources:
-            import jax
-
-            host = jax.process_index()
-            for src in self.event_sources:
-                # Prefer the aggregated per-host feed (a TimingCollector
-                # behind record_step turns this process's time into the
-                # full per-host vector); the raw (host, dt) feed is the
-                # legacy fallback under which a per-process detector can
-                # never flag by itself.
-                rec_step = getattr(src, "record_step", None)
-                if rec_step is not None:
-                    rec_step(dt)
-                    continue
-                rec = getattr(src, "record", None)
-                if rec is not None:
-                    rec(host, dt)
-        self._fire("on_step_end", step_idx, loss, dt)
-        self.poll()
+        with StepTraceAnnotation("spindle.step", step_num=self.step_count):
+            b = batches if batches is not None else self._step_batches()
+            t0 = time.perf_counter()
+            self.params, self.opt_state, loss = self.engine.train_step(
+                self.params, self.opt_state, b, self.optimizer,
+                on_wave=self._fire_wave,
+            )
+            with TraceAnnotation("spindle.loss_read"):
+                loss = float(loss)
+            dt = time.perf_counter() - t0
+            self.history.append(loss)
+            step_idx = self.step_count
+            self.step_count += 1
+            if self.event_sources:
+                host = jax.process_index()
+                for src in self.event_sources:
+                    # Prefer the aggregated per-host feed (a TimingCollector
+                    # behind record_step turns this process's time into the
+                    # full per-host vector); the raw (host, dt) feed is the
+                    # legacy fallback under which a per-process detector can
+                    # never flag by itself.
+                    rec_step = getattr(src, "record_step", None)
+                    if rec_step is not None:
+                        rec_step(dt)
+                        continue
+                    rec = getattr(src, "record", None)
+                    if rec is not None:
+                        rec(host, dt)
+            self._fire("on_step_end", step_idx, loss, dt)
+            self.poll()
         return loss
 
     def _step_batches(self) -> Dict[str, Dict]:
