@@ -52,7 +52,7 @@ class DemoObserver(SessionCallbacks):
     def on_replan(self, session, event, old_plan, new_plan, info):
         print(f"  re-plan on {event.kind}({event.task}): {info.mode} "
               f"({info.planning_seconds*1e3:.1f} ms planner, "
-              f"{info.closures_cached} engine closures kept)")
+              f"{info.closures_cached} engine step roles kept)")
 
 
 def verify_engine(session) -> None:

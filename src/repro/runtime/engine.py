@@ -4,10 +4,12 @@ The four runtime steps of the paper map onto JAX as follows:
 
   (1) **Localization** — every PlanStep (a sliced MetaOp on a fixed device
       group) becomes a pure segment function over the owning component
-      instance's params; on a multi-device runtime it is dispatched onto the
-      step's sub-mesh (async dispatch ⇒ steps of one wave run concurrently
-      on disjoint groups — the SPMD-engine analogue of per-group NCCL
-      streams, DESIGN.md §3).
+      instance's params, compiled once per step role into two programs: a
+      forward that also returns ``jax.vjp``'s residuals, and the pull that
+      consumes them.  On a multi-device runtime both run on the step's
+      sub-mesh, following the shardings of their placed inputs (async
+      dispatch ⇒ steps of one wave run concurrently on disjoint groups —
+      the SPMD-engine analogue of per-group NCCL streams, DESIGN.md §3).
   (2) **Intra-task data dependency** — inter-wave data flow is the engine
       moving the producer's output activation to the consumer's device
       group (``device_put`` resharding = the paper's copy/shard/concat/
@@ -17,9 +19,9 @@ The four runtime steps of the paper map onto JAX as follows:
       accumulate across all its per-task uses (realized as Σ over uses here,
       = the group all-reduce on hardware; optionally int8-compressed for
       island-crossing groups via repro.optim.compress).
-  (4) **Training step** — forward wave-by-wave under ``jax.vjp`` (closures
-      kept per step), backward in reverse wave order, group-wise gradient
-      sync, optimizer update.
+  (4) **Training step** — forward wave-by-wave through the role programs
+      (residuals kept per step), backward in reverse wave order by their
+      pulls, group-wise gradient sync, optimizer update.
 
 Numerical contract (tested): ``loss_and_grads`` ≡ ``jax.value_and_grad`` of
 ``MTModel.reference_loss`` for ANY planner-produced plan.
@@ -47,9 +49,77 @@ class _StepRecord:
     inst: str
     kind: str  # entry | mid | loss
     pred_order: List[int]  # meta_ids whose activations were inputs (entry)
-    vjp_fn: Any
+    role: "_Role"
+    args: Tuple  # the forward's arguments, handed to the pull again
+    residuals: "_Residuals"
     is_loss: bool
     out_like: Any = None  # output array (placement template for cotangents)
+
+
+class _Residuals:
+    """The part of a ``jax.vjp`` VJP that a compiled forward returns.
+
+    A VJP's leaves include the primal inputs it saved (parameters,
+    activations, batch arrays); a compiled program would copy each one into
+    a fresh output buffer.  ``own`` holds only the leaves the forward
+    computed; ``layout`` (static: the VJP's tree and, per leaf, its source)
+    lets the pull rebuild the VJP from ``own`` and the forward's arguments."""
+
+    def __init__(self, own: List[Any], layout: Tuple):
+        self.own = own
+        self.layout = layout
+
+    @classmethod
+    def split(cls, vjp, args) -> "_Residuals":
+        leaves, tree = jax.tree.flatten(vjp)
+        arg_pos = {id(x): i for i, x in enumerate(jax.tree.leaves(args))}
+        own, src = [], []
+        for leaf in leaves:
+            i = arg_pos.get(id(leaf))
+            if i is None:
+                src.append((False, len(own)))
+                own.append(leaf)
+            else:
+                src.append((True, i))
+        return cls(own, (tree, tuple(src)))
+
+    def join(self, args):
+        tree, src = self.layout
+        arg_leaves = jax.tree.leaves(args)
+        return jax.tree.unflatten(
+            tree, [arg_leaves[i] if is_arg else self.own[i]
+                   for is_arg, i in src]
+        )
+
+
+jax.tree_util.register_pytree_node(
+    _Residuals, lambda r: (r.own, r.layout),
+    lambda layout, own: _Residuals(list(own), layout),
+)
+
+
+@dataclass(frozen=True)
+class _Role:
+    """One step role's two compiled programs over its segment
+    ``seg(batches, inst_params, *ins)``:
+
+    - ``fwd(batches, inst_params, *ins) -> (out, residuals)``;
+    - ``pull(residuals, (batches, inst_params, *ins), g_out)
+      -> (d_inst_params, *d_ins)``."""
+
+    fwd: Callable
+    pull: Callable
+
+    @classmethod
+    def compile(cls, seg: Callable) -> "_Role":
+        def fwd(batches, inst_params, *ins):
+            out, vjp = jax.vjp(partial(seg, batches), inst_params, *ins)
+            return out, _Residuals.split(vjp, (batches, inst_params, *ins))
+
+        def pull(res, args, g_out):
+            return res.join(args)(g_out)
+
+        return cls(jax.jit(fwd), jax.jit(pull))
 
 
 class WaveEngine:
@@ -59,10 +129,16 @@ class WaveEngine:
         #: plan device ids must be real devices (checked on init and rebind)
         self._check_devices = distributed
         self.distributed = distributed and jax.device_count() > 1
-        # Step-closure cache, keyed by plan-id-independent step identity
-        # (instance, component, layer range, predecessor roles) — survives
-        # rebind() so replanned plans reuse closures for unchanged steps.
-        self._fn_cache: Dict[Tuple, Callable] = {}
+        # Step-role cache: the compiled (fwd, pull) pair per plan-id-
+        # independent step identity (instance, component spec, task set,
+        # predecessor roles, layer range, loss flag) — survives rebind() so
+        # replanned plans reuse the programs of unchanged steps.
+        self._fn_cache: Dict[Tuple, _Role] = {}
+        #: role programs built, and forward and pull calls served by a
+        #: program already in the cache
+        self.role_stats: Dict[str, int] = {
+            "built": 0, "fwd_hits": 0, "pull_hits": 0,
+        }
         # Device-group mesh cache (distributed mode): one Mesh per distinct
         # device tuple, shared by activation and parameter placement.
         self._mesh_cache: Dict[Tuple[int, ...], jax.sharding.Mesh] = {}
@@ -110,21 +186,23 @@ class WaveEngine:
                model: Optional[MTModel] = None) -> Dict[str, int]:
         """Swap in a replanned/cached plan — and optionally a shifted model.
 
-        Only the cheap plan-derived lookups are rebuilt; the per-step
-        closures in ``_fn_cache`` are keyed independently of MetaOp
-        numbering, so steps whose (instance, layer range, inputs) identity
-        is unchanged keep their closures even when the new plan slices or
-        renumbers MetaOps differently.  Returns ``closures_cached`` — the
-        number of closures retained for potential reuse; actual reuse
-        happens on the next ``loss_and_grads`` call (steps whose identity
-        changed rebuild then), observable as the cache size staying flat.
+        Only the cheap plan-derived lookups are rebuilt; the compiled step
+        roles in ``_fn_cache`` are keyed independently of MetaOp numbering,
+        so steps whose (instance, layer range, inputs) identity is unchanged
+        keep their programs even when the new plan slices or renumbers
+        MetaOps differently.  Returns ``closures_cached`` — the number of
+        roles retained for potential reuse; actual reuse happens on the next
+        ``loss_and_grads`` call (steps whose identity changed build and
+        compile their role then), observable as the cache size staying flat
+        and in ``role_stats``.
 
         When ``model`` is given (a task arrived/completed mid-run and the
         MTModel was rebuilt for the new task set), the engine rebinds to it
-        while KEEPING the closure cache: closures are pure in the component
-        spec + call-time params/batches, and their keys carry instance/
-        component/task roles, so steps shared between the old and new task
-        sets reuse their closures instead of rebuilding.
+        while KEEPING the role cache: a role's programs are pure in its key,
+        which carries the component spec itself, and take params and batches
+        as arguments, so steps shared between the old and new task sets reuse
+        their programs, a same-named component whose spec changed builds new
+        ones, and no program pins a retired model.
         """
         ref_model = model if model is not None else self.model
         self._validate_devices(plan)
@@ -256,21 +334,23 @@ class WaveEngine:
                         placed[pkey] = inst_p
                     if lo == 0:
                         preds, pred_info = self._entry_preds(mid)
-                        pred_acts = [self._put(acts[p], step) for p in preds]
-                        fn = self._make_entry_fn(
+                        ins = [self._put(acts[p], step) for p in preds]
+                        role = self._entry_role(
                             c, inst, pred_info, lo, hi, is_loss_step, task
                         )
-                        out, vjp = jax.vjp(
-                            partial(fn, batches), inst_p, *pred_acts
-                        )
-                        rec = _StepRecord(step, mid, inst, "entry", preds, vjp,
-                                          is_loss_step, out_like=out)
+                        kind = "entry"
                     else:
-                        h_in = self._put(acts[mid], step)
-                        fn = self._make_mid_fn(c, inst, lo, hi, is_loss_step, task)
-                        out, vjp = jax.vjp(partial(fn, batches), inst_p, h_in)
-                        rec = _StepRecord(step, mid, inst, "mid", [], vjp,
-                                          is_loss_step, out_like=out)
+                        preds = []
+                        ins = [self._put(acts[mid], step)]
+                        role = self._mid_role(c, inst, lo, hi, is_loss_step, task)
+                        kind = "mid"
+                    args = (
+                        {t: batches[t] for t in self._tasks_of(task)},
+                        inst_p, *ins,
+                    )
+                    out, res = role.fwd(*args)
+                    rec = _StepRecord(step, mid, inst, kind, preds, role, args,
+                                      res, is_loss_step, out_like=out)
                     records.append(rec)
                     used.update(d.id for d in out.devices())
                     if is_loss_step:
@@ -307,7 +387,8 @@ class WaveEngine:
                 return y
             return jax.device_put(y, like.sharding)
 
-        for rec in reversed(records):
+        while records:
+            rec = records.pop()  # reverse order; residuals freed once pulled
             mid = rec.meta_id
             if not rec.is_loss and mid not in cot:
                 continue  # activation never used (defensive)
@@ -320,7 +401,8 @@ class WaveEngine:
                     g_out = jax.tree.map(
                         lambda g, o: _same_place(g, o), g_out, rec.out_like
                     ) if rec.out_like is not None else g_out
-                pulls = rec.vjp_fn(g_out)
+                pulls = rec.role.pull(rec.residuals, rec.args, g_out)
+                self.role_stats["pull_hits"] += 1
             d_params, d_ins = pulls[0], pulls[1:]
             with TraceAnnotation(f"spindle.grad_acc:{rec.inst}"):
                 grads[rec.inst] = _acc(grads[rec.inst], d_params)
@@ -337,35 +419,45 @@ class WaveEngine:
         ts = task_str.split("+")
         return sorted(ts, key=self.flow_order.index)
 
-    def _make_entry_fn(self, c: ExecComponent, inst, pred_info, lo, hi,
-                       is_loss, task_str):
-        """Cached entry-step closure.
+    def _role(self, key: Tuple, make_seg: Callable[[], Callable]) -> _Role:
+        role = self._fn_cache.get(key)
+        if role is not None:
+            self.role_stats["fwd_hits"] += 1
+            return role
+        role = self._fn_cache[key] = _Role.compile(make_seg())
+        self.role_stats["built"] += 1
+        return role
 
-        The cache key carries no MetaOp ids — only roles (instance,
-        component, task set, predecessor (task, component) layout, layer
-        range) — and ``batches`` is supplied at call time, so the closure
-        survives rebind() across replans.
+    def _entry_role(self, c: ExecComponent, inst, pred_info, lo, hi,
+                    is_loss, task_str) -> _Role:
+        """Compiled entry-step role.
+
+        The cache key carries no MetaOp ids — only roles (instance, the
+        component spec, task set, predecessor (task, component) layout,
+        layer range) — and params and ``batches`` are arguments, so the
+        role survives rebind() across replans.
         """
-        key = ("entry", inst, c.name, task_str, pred_info, lo, hi, is_loss)
-        cached = self._fn_cache.get(key)
-        if cached is not None:
-            return cached
-        # Closures resolve the model AND the component spec at CALL time
-        # through the engine: rebind(model=...) never pins retired models
-        # in the cache across a long-running session's task-set shifts,
-        # and a factory that redefines a same-named component applies the
-        # current spec rather than a stale captured one.
+        key = ("entry", inst, c, task_str, pred_info, lo, hi, is_loss)
+        return self._role(key, lambda: self._entry_seg(
+            c, pred_info, lo, hi, is_loss, self._tasks_of(task_str)))
+
+    def _mid_role(self, c: ExecComponent, inst, lo, hi, is_loss,
+                  task_str) -> _Role:
+        key = ("mid", inst, c, task_str, lo, hi, is_loss)
+        return self._role(key, lambda: self._mid_seg(
+            c, lo, hi, is_loss, self._tasks_of(task_str)))
+
+    def _entry_seg(self, c, pred_info, lo, hi, is_loss, tasks):
+        # The segment reaches the model through the engine, and only while
+        # it is traced: no program pins a model that rebind() retired.
         engine = self
-        cname = c.name
-        tasks = self._tasks_of(task_str)
         pos_by_task = {
             t: [i for i, (pt, _) in enumerate(pred_info) if pt == t]
             for t in tasks
         }
 
-        def fn(batches, inst_params, *pred_acts):
+        def seg(batches, inst_params, *pred_acts):
             model = engine.model
-            c = model.components[cname]
             if c.kind == "contrastive":
                 inputs = {pc: a for (_, pc), a in zip(pred_info, pred_acts)}
                 return model.loss_op(inst_params, c, inputs, batches[tasks[0]])
@@ -375,43 +467,19 @@ class WaveEngine:
                 inputs = {pred_info[i][1]: pred_acts[i] for i in pos_by_task[t]}
                 hs.append(model.entry(inst_params, c, inputs, batches[t]))
             h = hs[0] if len(hs) == 1 else jnp.concatenate(hs, axis=0)
-            for lp in inst_params["layers"][lo:hi]:
-                h = model.apply_layer(c, lp, h)
-            if is_loss:
-                labels = jnp.concatenate(
-                    [batches[t]["labels"] for t in tasks], axis=0
-                ) if len(tasks) > 1 else batches[tasks[0]]["labels"]
-                return model.loss_op(
-                    inst_params, c, {}, {"labels": labels}, h=h
-                )
-            return h
+            return _layers_and_loss(model, c, inst_params, h, lo, hi,
+                                    is_loss, batches, tasks)
 
-        self._fn_cache[key] = fn
-        return fn
+        return seg
 
-    def _make_mid_fn(self, c: ExecComponent, inst, lo, hi, is_loss, task_str):
-        key = ("mid", inst, c.name, task_str, lo, hi, is_loss)
-        cached = self._fn_cache.get(key)
-        if cached is not None:
-            return cached
-        engine = self  # call-time model/spec lookup — see _make_entry_fn
-        cname = c.name
-        tasks = self._tasks_of(task_str)
+    def _mid_seg(self, c, lo, hi, is_loss, tasks):
+        engine = self  # trace-time model lookup — see _entry_seg
 
-        def fn(batches, inst_params, h):
-            model = engine.model
-            c = model.components[cname]
-            for lp in inst_params["layers"][lo:hi]:
-                h = model.apply_layer(c, lp, h)
-            if is_loss:
-                labels = jnp.concatenate(
-                    [batches[t]["labels"] for t in tasks], axis=0
-                ) if len(tasks) > 1 else batches[tasks[0]]["labels"]
-                return model.loss_op(inst_params, c, {}, {"labels": labels}, h=h)
-            return h
+        def seg(batches, inst_params, h):
+            return _layers_and_loss(engine.model, c, inst_params, h, lo, hi,
+                                    is_loss, batches, tasks)
 
-        self._fn_cache[key] = fn
-        return fn
+        return seg
 
     # ------------------------------------------------------------------
     def train_step(self, params, opt_state, batches, optimizer, *,
@@ -421,3 +489,17 @@ class WaveEngine:
         with TraceAnnotation("spindle.optim"):
             new_params, new_state = optimizer.update(grads, opt_state, params)
         return new_params, new_state, loss
+
+
+def _layers_and_loss(model: MTModel, c: ExecComponent, inst_params, h, lo, hi,
+                     is_loss, batches, tasks):
+    """Layers ``lo:hi`` of a component instance, then its loss where the
+    segment ends the component's chain."""
+    for lp in inst_params["layers"][lo:hi]:
+        h = model.apply_layer(c, lp, h)
+    if not is_loss:
+        return h
+    labels = jnp.concatenate(
+        [batches[t]["labels"] for t in tasks], axis=0
+    ) if len(tasks) > 1 else batches[tasks[0]]["labels"]
+    return model.loss_op(inst_params, c, {}, {"labels": labels}, h=h)

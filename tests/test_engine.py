@@ -67,3 +67,105 @@ def test_engine_wave_structure_respects_plan():
     for widx, steps in waves.items():
         devs = [d for s in steps for d in s.devices]
         assert len(devs) == len(set(devs))
+
+
+# --------------------------------------------------------------------------
+# Compiled step roles
+# --------------------------------------------------------------------------
+
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _assert_matches(loss, grads, ref_loss, ref_grads):
+    assert float(jnp.abs(loss - ref_loss)) < 1e-5
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+
+
+def test_engine_repeat_calls_build_and_compile_nothing():
+    """After the first call every plan step runs a cached role program:
+    no role is built and XLA compiles nothing."""
+    model, batches = tiny_multitask_clip()
+    params = model.init(jax.random.PRNGKey(0))
+    p = plan(model.graph, ClusterSpec(n_devices=8, island_size=4))
+    eng = WaveEngine(model, p)
+    jax.block_until_ready(eng.loss_and_grads(params, batches))
+    built = eng.role_stats["built"]
+    assert built == len(eng._fn_cache) > 0
+
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == BACKEND_COMPILE:
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for _ in range(2):
+            before = dict(eng.role_stats)
+            jax.block_until_ready(eng.loss_and_grads(params, batches))
+            assert eng.role_stats["built"] == built
+            assert eng.role_stats["fwd_hits"] - before["fwd_hits"] == len(p.steps)
+            assert eng.role_stats["pull_hits"] - before["pull_hits"] == len(p.steps)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+
+
+@pytest.mark.parametrize("field,value", [("n_heads", 2), ("d_ff", 48)])
+def test_engine_rebind_to_a_changed_component_runs_its_new_spec(field, value):
+    """A model whose same-named component changed its spec gets programs of
+    its own: no stale program runs after rebind(model=...)."""
+    import dataclasses
+
+    from repro.runtime import MTModel
+
+    model1, batches = tiny_ofasys()
+    cluster = ClusterSpec(n_devices=8, island_size=4)
+    eng = WaveEngine(model1, plan(model1.graph, cluster))
+    params1 = model1.init(jax.random.PRNGKey(0))
+    eng.loss_and_grads(params1, batches)
+    built = eng.role_stats["built"]
+
+    comps = [dataclasses.replace(c, **{field: value}) if c.name == "lm" else c
+             for c in model1.components.values()]
+    model2 = MTModel(comps, model1.flows)
+    # n_heads keeps every parameter's shape: only the spec tells them apart
+    params2 = params1 if field == "n_heads" else model2.init(jax.random.PRNGKey(0))
+    ref_loss, ref_grads = jax.value_and_grad(model2.reference_loss)(
+        params2, batches
+    )
+    if field == "n_heads":  # the old spec gives another loss on these params
+        old_loss = model1.reference_loss(params2, batches)
+        assert float(jnp.abs(ref_loss - old_loss)) > 1e-4
+    eng.rebind(plan(model2.graph, cluster), model=model2)
+    loss, grads = eng.loss_and_grads(params2, batches)
+    _assert_matches(loss, grads, ref_loss, ref_grads)
+    assert eng.role_stats["built"] > built  # the decoder's roles are new
+
+
+def test_engine_replan_keeps_the_unchanged_roles_programs():
+    """A replan that slices one tower differently builds only the roles it
+    changed; every other step reuses its compiled programs."""
+    model, batches = tiny_ofasys()
+    params = model.init(jax.random.PRNGKey(0))
+    ref_loss, ref_grads = jax.value_and_grad(model.reference_loss)(
+        params, batches
+    )
+    eng = WaveEngine(model, plan(model.graph,
+                                 ClusterSpec(n_devices=8, island_size=4)))
+    eng.loss_and_grads(params, batches)
+    before = dict(eng._fn_cache)
+    stats = dict(eng.role_stats)
+
+    p2 = plan(model.graph, ClusterSpec(n_devices=2, island_size=2))
+    eng.rebind(p2)
+    loss, grads = eng.loss_and_grads(params, batches)
+    _assert_matches(loss, grads, ref_loss, ref_grads)
+
+    new = set(eng._fn_cache) - set(before)
+    built = eng.role_stats["built"] - stats["built"]
+    reused = eng.role_stats["fwd_hits"] - stats["fwd_hits"]
+    assert built == len(new) > 0
+    assert reused > 0 and built + reused == len(p2.steps)
+    assert all(eng._fn_cache[k] is role for k, role in before.items())
