@@ -24,7 +24,7 @@ def _half(batches):
 
 @contextlib.contextmanager
 def planted(kind: str):
-    from repro.runtime.engine import WaveEngine
+    from .program import WaveEngine
 
     if kind == "unchanged":
         name, orig = "train_step", WaveEngine.train_step

@@ -4,13 +4,21 @@ window, the comparison of every step with the reference, and the result line.
 ``run_cell`` is what ``bench/run.py`` calls on the chip; the tests call it on
 the CPU at a tiny size. Everything specific to a cell is found by name:
 ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``,
-``bench/limits/<cell>.json`` and ``bench/metrics/<metric>.py``.
+``bench/limits/<cell>.json``, ``bench/metrics/<metric>.py``, and the code of
+the configuration's architecture, ``bench/arch/<arch>/`` (``spec.arch``).
+
+A metric's reader gets one dict, ``ctx``: the cell's ``spec``, ``chips``,
+``setup_s``, ``step_s``, ``steps`` and ``window_s`` of the window and its
+``flops_per_step``; in a traced run also the host spans' ``plan_ms``,
+``fwd_ms`` and ``bwd_ms``, ``peak_flops``, ``trace`` (``bench/trace.reduce``:
+busy and idle, and ``op_s``, the seconds per chip of every device operation
+by its folded name) and ``spans`` (``bench/spans.program_spans``: the
+program's own spans, reduced once; ``None`` where the program opened none).
 """
 
 from __future__ import annotations
 
 import gc
-import importlib.util
 import math
 import pathlib
 import shutil
@@ -20,7 +28,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 
-from . import compare, flops, reference, spec as specmod
+from . import compare, flops, reference, spans as spansmod, spec as specmod
 from . import trace as tracemod
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -28,7 +36,6 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CHECK_STEPS = 3
 #: further steps, at most, until one compiles nothing
 WARM_STEPS = 3
-WINDOW_SPAN = "bench_window"
 
 
 class CompileCounter:
@@ -45,11 +52,8 @@ class CompileCounter:
 
 
 def load_reader(name: str) -> Callable[[Dict], Optional[float]]:
-    path = specmod.BENCH / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return specmod.load_file(specmod.BENCH / "metrics" / f"{name}.py",
+                             f"bench_metric_{name}").read
 
 
 def metrics_for(bench: Dict, cell: str, traced: bool) -> List[Dict]:
@@ -75,9 +79,9 @@ def prepare(workload: str):
     src = str(specmod.ROOT / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
-    from repro.launch.compile_cache import enable_compile_cache
+    from . import program
 
-    enable_compile_cache()
+    program.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     bench = specmod.load_benchmark()
     cell = specmod.find_cell(bench, workload)
@@ -134,7 +138,7 @@ def measure(session, seconds: float, compiles: CompileCounter, spans=None
     jax.block_until_ready(program.state(session))
     n0 = compiles.n
     losses = []
-    with jax.profiler.TraceAnnotation(WINDOW_SPAN):
+    with jax.profiler.TraceAnnotation(tracemod.WINDOW):
         t0 = time.perf_counter()
         while True:
             if spans is not None:
@@ -205,6 +209,15 @@ def reference_readings(spec: Dict, seed: int, steps: int = CHECK_STEPS,
                               specmod.make_batches(spec, seed), steps, control)
 
 
+def trace_context(events, chips: int, steps: int) -> Dict[str, Any]:
+    """The readers' ``trace`` and ``spans`` from one traced window's
+    events, each reduced once."""
+    red = tracemod.reduce(events, chips=chips, window=tracemod.WINDOW)
+    prog = spansmod.program_spans(events, window=tracemod.WINDOW, chips=chips,
+                                  steps=steps)
+    return {"trace": red, "spans": prog if prog["phases"] else None}
+
+
 def check_lines(checks: Dict[str, Dict]) -> List[str]:
     return [f"check {k}: {c['value']!r} limit {c['limit']!r}"
             for k, c in checks.items()]
@@ -238,6 +251,7 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, traced: bool,
               "count": jax.device_count(),
               "memory_peak_bytes": win["memory_peak_bytes"]}
     ctx: Dict[str, Any] = {
+        "spec": spec,
         "setup_s": win["setup_s"],
         "step_s": win["window_s"] / win["steps"],
         "steps": win["steps"],
@@ -255,9 +269,9 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, traced: bool,
         "compared_steps": len(prog["losses"]),
     }
     if traced:
-        red = tracemod.reduce(tracemod.load(out_dir / "trace"), chips=chips,
-                              window=WINDOW_SPAN)
-        ctx["trace"] = red
+        ctx.update(trace_context(tracemod.load(out_dir / "trace"), chips,
+                                 win["steps"]))
+        red, prog_spans = ctx["trace"], ctx["spans"]
         ctx["peak_flops"] = peak_flops(dev0.device_kind)
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
@@ -265,6 +279,9 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, traced: bool,
                                "idle_gaps": red["idle_gaps"]}
         log(f"trace: {red['n_device_ops']} device ops, busy {red['busy_s']!r} s "
             f"of {red['window_s']!r} s per chip")
+        if prog_spans is not None:
+            log(f"trace: program spans cover {prog_spans['covered_pct']!r}% of "
+                f"the idle time; idle by span {prog_spans['idle_by_span']!r}")
     metrics = {}
     for m in metrics_for(bench, cell["name"], traced):
         value = load_reader(m["name"])(ctx)

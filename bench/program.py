@@ -1,10 +1,13 @@
 """The system under test, as the benchmark drives it.
 
-The one module of the benchmark that imports the program: it builds the
-cell's ``MTModel`` from the plain spec, binds a ``SpindleSession`` (planner
-``spindle``) over the cell's chips, and reads what the comparison needs from
-the session's state. Weights and batches come from ``bench/spec.py``; the
-session's own ``init`` and data cursor hand them to the program.
+With ``bench/arch/<arch>/program.py``, the only module of the benchmark that
+imports the program. What every architecture shares is here: the compile
+cache of the checkout, the engine the faults patch, a ``SpindleSession``
+(planner ``spindle``) bound over the cell's chips to the model that the
+architecture's ``build_model`` makes from the plain spec, and what the
+comparison reads from the session's state. Weights and batches come from
+``bench/spec.py``; the session's own ``init`` and data cursor hand them to
+the program.
 """
 
 from __future__ import annotations
@@ -18,42 +21,20 @@ import jax.numpy as jnp
 from repro.core.placement import ClusterSpec
 from repro.optim import AdamW
 from repro.parallel import mesh_over_devices
-from repro.runtime.mtmodel import ExecComponent, ExecFlow, MTModel
 from repro.session import SessionCallbacks, SessionConfig, SpindleSession
 
-from . import reference
+# for ``harness.prepare`` and ``bench/faults.py``, which import nothing of
+# the program themselves
+from repro.launch.compile_cache import enable_compile_cache  # noqa: F401
+from repro.runtime.engine import WaveEngine  # noqa: F401
+
+from . import reference, spec as specmod
 
 
-class SeededModel(MTModel):
-    """``MTModel`` whose ``init`` hands over the benchmark's seeded weights
-    (made in one jitted call) instead of initialising leaf by leaf."""
-
-    def __init__(self, components, flows, weights):
-        super().__init__(components, flows)
-        self._weights = weights
-
-    def init(self, rng):
-        weights, self._weights = self._weights, None
-        if weights is None:
-            raise RuntimeError("the seeded weights were handed over already")
-        return weights
-
-
-def build_model(spec: Dict[str, Any], weights) -> SeededModel:
-    comps = [
-        ExecComponent(
-            name, c["kind"], c["n_layers"], c["d_model"], c["n_heads"],
-            d_ff=c["d_ff"], vocab=c["vocab"], shared=c["shared"],
-            merge_shared=c["merge_shared"],
-        )
-        for name, c in spec["components"].items()
-    ]
-    flows = [
-        ExecFlow(f["task"], tuple(tuple(b) for b in f["branches"]),
-                 tuple(f["join"]), f["batch"], dict(f["seq"]))
-        for f in spec["flows"]
-    ]
-    return SeededModel(comps, flows, weights)
+def build_model(spec: Dict[str, Any], weights):
+    """The program's model of the spec's architecture, holding ``weights``
+    (``None`` where only its shapes and functions are wanted)."""
+    return specmod.arch(spec, "program").build_model(spec, weights)
 
 
 def _bytes_limit(dev) -> float:

@@ -23,11 +23,12 @@ in which no op of the chip's ``XLA Ops`` line runs. Host spans of other
 names, the benchmark's own among them, are ignored here, even where they
 overlap the program's out of order.
 
-The per-layer readers call ``of(ctx)``, which reads the trace that
-``bench/run.py`` leaves under ``.bench_out/trace`` once per run, keeping
-only the events read here (``load``).
+The harness reduces a traced run's spans once, from the events it loaded
+for ``bench/trace.reduce``, and hands them to the per-layer readers as
+``ctx["spans"]``; the readers call ``phase_ms``.
 ``python3 -m bench.spans --steps <n> [--chips <n>] [<trace dir>]`` prints
-the reduction of a trace as JSON.
+the reduction of a trace as JSON, by default of the one ``bench/run.py``
+leaves under ``.bench_out/trace``, read by ``load``.
 """
 
 from __future__ import annotations
@@ -40,8 +41,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import spec as specmod
 from . import trace
-from .harness import WINDOW_SPAN as WINDOW
-from .trace import DEVICE_PREFIX, OPS_LINE, Event
+from .trace import DEVICE_PREFIX, OPS_LINE, WINDOW, Event
 
 PREFIX = "spindle."
 #: where ``bench/run.py`` has the harness write a traced run's trace
@@ -108,22 +108,9 @@ def load(trace_dir: pathlib.Path) -> List[Event]:
     return out
 
 
-def _device_planes(events: Sequence[Event], chips: int) -> List[str]:
-    planes = sorted({e.plane for e in events if e.plane.startswith(DEVICE_PREFIX)},
-                    key=lambda p: int(p[len(DEVICE_PREFIX):].split()[0]))[:chips]
-    if len(planes) < chips:
-        raise ValueError(f"the trace has {len(planes)} device planes, the cell "
-                         f"uses {chips}")
-    return planes
-
-
 def program_spans(events: Sequence[Event], *, window: str, chips: int,
                   steps: int, ops_line: str = OPS_LINE) -> Dict:
-    wins = [e for e in events if e.name == window
-            and not e.plane.startswith(DEVICE_PREFIX)]
-    if not wins:
-        raise ValueError(f"no host span {window!r} in the trace")
-    lo, hi = wins[0].start_ns, wins[0].end_ns
+    lo, hi = trace.window_bounds(events, window)
     spans = []
     for e in events:
         if e.name.startswith(PREFIX) and not e.plane.startswith(DEVICE_PREFIX):
@@ -131,7 +118,7 @@ def program_spans(events: Sequence[Event], *, window: str, chips: int,
             if t > s:
                 spans.append((s, t, e.name))
     stretches = owned_stretches(spans)
-    planes = _device_planes(events, chips)
+    planes = trace.device_planes(events, chips)
 
     self_ns: Dict[str, float] = {}
     for s, t, name in stretches:
@@ -175,38 +162,10 @@ def program_spans(events: Sequence[Event], *, window: str, chips: int,
     }
 
 
-def of(ctx: Dict) -> Optional[Dict]:
-    """The program's spans in the traced run that ``ctx`` describes, reduced
-    once and kept in ``ctx["spans"]``. ``None`` where the run was not traced,
-    its trace is not the one under ``TRACE_DIR``, or the program opened no
-    ``spindle.*`` span in the window."""
-    if "spans" not in ctx:
-        ctx["spans"] = _read(ctx)
-    return ctx["spans"]
-
-
-def _read(ctx: Dict) -> Optional[Dict]:
-    tr = ctx.get("trace")
-    if not tr or not ctx.get("steps"):
-        return None
-    try:
-        events = load(TRACE_DIR)
-    except FileNotFoundError:
-        return None
-    wins = [e for e in events if e.name == WINDOW
-            and not e.plane.startswith(DEVICE_PREFIX)]
-    # the window ``reduce`` read for this run, to the last bit
-    if not wins or (wins[0].end_ns - wins[0].start_ns) * 1e-9 != tr["window_s"]:
-        return None
-    red = program_spans(events, window=WINDOW, chips=ctx["chips"],
-                        steps=ctx["steps"])
-    return red if red["phases"] else None
-
-
 def phase_ms(ctx: Dict, *phases: str) -> Optional[float]:
     """Self ms per traced step summed over ``phases``; ``None`` where the
-    run has no span of any of them."""
-    red = of(ctx)
+    run was not traced or has no span of any of them."""
+    red = ctx.get("spans")
     if red is None or not any(p in red["phases"] for p in phases):
         return None
     return sum(red["phases"][p]["ms"] for p in phases if p in red["phases"])

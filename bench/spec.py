@@ -3,18 +3,23 @@
 Nothing here imports the program. ``load_spec`` turns a configuration file
 (``bench/configs/<config>.json``) and a traffic file
 (``bench/traffic/<traffic>.json``) into one plain dict that the harness, the
-program adapter and the reference all read. ``make_weights`` and
-``make_batches`` build the parameters and the input batches from a seed, each
-in one jitted call on the default device; the same seed gives the same arrays
-in any process (no ``hash()``, no process state).
+program adapter and the reference all read. ``arch`` finds the code that
+belongs to the configuration's architecture (its ``"arch"`` key) under
+``bench/arch/<arch>/``. ``make_weights`` and ``make_batches`` build the
+parameters and the input batches from a seed, each in one jitted call on the
+default device; the same seed gives the same arrays in any process (no
+``hash()``, no process state).
 """
 
 from __future__ import annotations
 
 import functools
+import importlib.util
 import json
 import math
 import pathlib
+import sys
+from types import ModuleType
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -50,21 +55,53 @@ def load_traffic(name: str) -> Dict[str, Any]:
     return read_json(BENCH / "traffic" / f"{name}.json")
 
 
+def load_file(path: pathlib.Path, name: str) -> ModuleType:
+    """The Python file at ``path`` as a module registered as ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_module(arch_name: str, part: str) -> ModuleType:
+    path = BENCH / "arch" / arch_name / f"{part}.py"
+    if not path.is_file():
+        raise SystemExit(f"no {part}.py for architecture {arch_name!r} "
+                         f"under bench/arch")
+    return load_file(path, f"bench_arch_{arch_name}_{part}")
+
+
+def arch(spec: Dict[str, Any], part: str = "reference") -> ModuleType:
+    """The module ``bench/arch/<arch>/<part>.py`` of a spec's (or a
+    configuration's) architecture, loaded once per process.
+
+    ``reference`` is plain ``jax.numpy`` and imports nothing of the program:
+    ``param_shapes(spec)``, ``loss(spec, params, batch, mm)``,
+    ``forward_flops(spec)`` and ``tiny(cfg)``. ``program`` is the adapter,
+    ``build_model(spec, weights)``, and only ``bench/program.py`` asks for
+    it."""
+    return _arch_module(spec["arch"], part)
+
+
 def build_spec(cfg: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
-    """Components with their run depth, and the traffic's flows."""
+    """Components with their run depth, and the traffic's flows. Every key of
+    a component but its ``source`` is kept, so that an architecture reads
+    its own."""
     comps = {}
     for name, c in cfg["components"].items():
-        comps[name] = {
-            "kind": c["kind"],
-            "n_layers": cfg["layers"].get(name, 1),
-            "d_model": c["d_model"],
-            "n_heads": c.get("n_heads", 1),
-            "d_ff": c.get("d_ff", 4 * c["d_model"]),
-            "vocab": c.get("vocab", 0),
-            "seq": c.get("seq", 1),
-            "shared": bool(c.get("shared", False)),
-            "merge_shared": bool(c.get("merge_shared", False)),
-        }
+        comp = {k: v for k, v in c.items() if k != "source"}
+        comp.update(
+            n_layers=cfg["layers"].get(name, 1),
+            n_heads=c.get("n_heads", 1),
+            d_ff=c.get("d_ff", 4 * c["d_model"]),
+            vocab=c.get("vocab", 0),
+            seq=c.get("seq", 1),
+            shared=bool(c.get("shared", False)),
+            merge_shared=bool(c.get("merge_shared", False)),
+        )
+        comps[name] = comp
     tasks = cfg["tasks"][: traffic["tasks"]]
     used = {n for t in tasks for br in t["branches"] for n in br} | {
         n for t in tasks for n in t["join"]}
@@ -82,6 +119,7 @@ def build_spec(cfg: Dict[str, Any], traffic: Dict[str, Any]) -> Dict[str, Any]:
         })
     return {
         "name": cfg["name"],
+        "arch": cfg["arch"],
         "components": comps,
         "flows": flows,
         "optimizer": dict(cfg["optimizer"]),
@@ -111,68 +149,6 @@ def instances(spec: Dict[str, Any]) -> List[Tuple[str, str]]:
     return sorted(out)
 
 
-def in_dims(spec: Dict[str, Any], comp: str) -> Dict[str, int]:
-    """Widths of the components that feed ``comp`` in any flow (the
-    program keeps one projection per feeding component and instance)."""
-    dims = {}
-    comps = spec["components"]
-    for f in spec["flows"]:
-        for chain in f["branches"] + [f["join"]]:
-            for a, b in zip(chain, chain[1:]):
-                if b == comp:
-                    dims[a] = comps[a]["d_model"]
-        if f["join"] and f["join"][0] == comp:
-            for br in f["branches"]:
-                if br:
-                    dims[br[-1]] = comps[br[-1]]["d_model"]
-    return dims
-
-
-def param_shapes(spec: Dict[str, Any]) -> Dict[str, Any]:
-    """Parameter tree of ``(shape, init)`` leaves in the program's layout.
-
-    ``init`` is ``("normal", scale)`` or ``("const", value)``: the program's
-    own initialisation rule (normal weights scaled by 1/sqrt(fan-in),
-    embeddings by 0.02, norms at one, the contrastive temperature at
-    log 10)."""
-    tree: Dict[str, Any] = {}
-    for inst, comp in instances(spec):
-        c = spec["components"][comp]
-        d, ff = c["d_model"], c["d_ff"]
-
-        def dense(a, b):
-            return ((a, b), ("normal", 1.0 / math.sqrt(a)))
-
-        if c["kind"] == "contrastive":
-            tree[inst] = {
-                "proj": {src: dense(w, d)
-                         for src, w in sorted(in_dims(spec, comp).items())},
-                "logit_scale": ((), ("const", math.log(10.0))),
-            }
-            continue
-        p: Dict[str, Any] = {}
-        if c["kind"] == "decoder":
-            p["tok_embed"] = ((c["vocab"], d), ("normal", 0.02))
-            p["lm_head"] = dense(d, c["vocab"])
-            p["prefix_proj"] = {src: dense(w, d)
-                                for src, w in sorted(in_dims(spec, comp).items())}
-        ones = ((d,), ("const", 1.0))
-        p["layers"] = [
-            {
-                "norm1": {"scale": ones},
-                "attn": {"wq": dense(d, d), "wk": dense(d, d),
-                         "wv": dense(d, d), "wo": dense(d, d)},
-                "norm2": {"scale": ones},
-                "mlp": {"w_gate": dense(d, ff), "w_up": dense(d, ff),
-                        "w_down": dense(ff, d)},
-            }
-            for _ in range(c["n_layers"])
-        ]
-        p["final_norm"] = {"scale": ones}
-        tree[inst] = p
-    return tree
-
-
 def _is_leaf(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], tuple)
 
@@ -193,14 +169,17 @@ def frozen(spec: Dict[str, Any]) -> str:
 
 def make_weights(spec: Dict[str, Any], seed: int) -> Dict[str, Any]:
     """All float32 parameters from the seed, in one jitted call: one normal
-    draw cut into the leaves, each scaled by its rule."""
+    draw cut into the leaves of the architecture's ``param_shapes``, in
+    their order, each scaled by its rule. A leaf is ``(shape, init)``, with
+    ``init`` ``("normal", scale)`` or ``("const", value)``."""
     return _weights_fn(frozen(spec))(seed_keys(seed)["weights"])
 
 
 @functools.lru_cache(maxsize=4)
 def _weights_fn(key: str):
     spec = json.loads(key)
-    leaves, treedef = jax.tree.flatten(param_shapes(spec), is_leaf=_is_leaf)
+    leaves, treedef = jax.tree.flatten(arch(spec).param_shapes(spec),
+                                       is_leaf=_is_leaf)
     sizes = [math.prod(shape) if rule == "normal" else 0
              for shape, (rule, _) in leaves]
     starts = np.cumsum([0] + sizes)

@@ -3,13 +3,14 @@
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into flat
 events. ``reduce`` takes those events and:
 
-- finds the measured window, the host span named ``window``;
+- finds the measured window, the host span named ``window`` (the harness's
+  ``WINDOW``);
 - takes each device's operations (the ``ops_line`` line of its
   ``/device:TPU:<n>`` plane), clipped to the window, and their union as the
   time that device was busy; ``busy_s`` is the mean over the cell's chips and
   ``idle_pct`` is one minus busy over the window;
-- sums device time per operation for the top operations, as seconds per
-  chip: an event named by its HLO text (``%mul.1 = f32[8,257,4096]{...}
+- sums device time per operation, as seconds per chip (``op_s``, every
+  operation; ``device_ops``, the top ones): an event named by its HLO text (``%mul.1 = f32[8,257,4096]{...}
   multiply(...)``) counts under its instruction name and result shape
   (``mul f32[8,257,4096]``), any other under its name; ``.<n>`` suffixes
   are folded;
@@ -30,6 +31,8 @@ from typing import Dict, Iterable, List, Sequence, Tuple
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+#: the host span around the measured window
+WINDOW = "bench_window"
 SPAN_RE = re.compile(r"^(fwd_wave_\d+|bwd_and_update)$")
 TOP = 10
 
@@ -106,18 +109,29 @@ def _top(d: Dict[str, float]) -> List[List]:
     return [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:TOP]]
 
 
-def reduce(events: Sequence[Event], *, chips: int, window: str,
-           ops_line: str = OPS_LINE) -> Dict:
+def window_bounds(events: Sequence[Event], window: str) -> Tuple[float, float]:
+    """Start and end, in ns, of the first host span named ``window``."""
     wins = [e for e in events if e.name == window
             and not e.plane.startswith(DEVICE_PREFIX)]
     if not wins:
         raise ValueError(f"no host span {window!r} in the trace")
-    lo, hi = wins[0].start_ns, wins[0].end_ns
+    return wins[0].start_ns, wins[0].end_ns
+
+
+def device_planes(events: Sequence[Event], chips: int) -> List[str]:
+    """The planes of the first ``chips`` devices, in device order."""
     planes = sorted({e.plane for e in events if e.plane.startswith(DEVICE_PREFIX)},
                     key=lambda p: int(p[len(DEVICE_PREFIX):].split()[0]))[:chips]
     if len(planes) < chips:
         raise ValueError(f"the trace has {len(planes)} device planes, the cell "
                          f"uses {chips}")
+    return planes
+
+
+def reduce(events: Sequence[Event], *, chips: int, window: str,
+           ops_line: str = OPS_LINE) -> Dict:
+    lo, hi = window_bounds(events, window)
+    planes = device_planes(events, chips)
     spans = sorted((e.start_ns, e.end_ns, e.name) for e in events
                    if not e.plane.startswith(DEVICE_PREFIX)
                    and SPAN_RE.match(e.name))
@@ -153,12 +167,14 @@ def reduce(events: Sequence[Event], *, chips: int, window: str,
     window_s = (hi - lo) * 1e-9
     busy_s = sum(busy_ns) / len(planes) * 1e-9
     per_chip = 1e-9 / len(planes)
+    op_s = {k: v * per_chip for k, v in op_ns.items()}
     return {
         "window_s": window_s,
         "busy_s": busy_s,
         "busy_s_per_chip": [b * 1e-9 for b in busy_ns],
         "idle_pct": 100.0 * (1.0 - busy_s / window_s),
-        "device_ops": _top({k: v * per_chip for k, v in op_ns.items()}),
+        "op_s": op_s,
+        "device_ops": _top(op_s),
         "idle_gaps": _top({k: v * per_chip for k, v in gap_ns.items()}),
         "n_device_ops": sum(1 for e in events if e.plane in planes
                             and e.line == ops_line),

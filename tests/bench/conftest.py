@@ -1,7 +1,9 @@
 """Shared helpers for the benchmark's CPU tests: the checkout on the path,
 and tiny versions of the benchmark's configurations (same structure, small
-widths) for runs a test can hold."""
+widths, each shrunk by its architecture's ``tiny``) for runs a test can
+hold."""
 
+import json
 import pathlib
 import sys
 
@@ -12,25 +14,27 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+_BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+#: ``(config, traffic)`` for each configuration of BENCHMARK.json, with the
+#: traffic of its first cell
+CONFIG_CELLS = [
+    (cfg["name"], next(c["traffic"] for c in _BENCH["workloads"]
+                       if c["config"] == cfg["name"]))
+    for cfg in _BENCH["configs"]
+]
+CONFIG_IDS = [name for name, _ in CONFIG_CELLS]
+
 
 def tiny_spec(config: str, traffic: str, batch: int = 4):
-    """The configuration's components, tasks and sharing at width 32, 2
-    layers, 8 tokens and a 97-word vocabulary (one width and length keep the
-    eager engine's compilations few)."""
+    """The configuration shrunk by its architecture's ``tiny``, under its
+    traffic with ``batch`` samples per task."""
     from bench import spec
 
     cfg = spec.load_config(config)
-    for name, c in cfg["components"].items():
-        c.update(d_model=32, n_heads=4, d_ff=64, seq=8)
-        if c["kind"] == "decoder":
-            c.update(vocab=97)
-        if name in cfg["layers"]:
-            cfg["layers"][name] = 2
     tr = dict(spec.load_traffic(traffic), batch_per_task=batch)
-    return spec.build_spec(cfg, tr)
+    return spec.build_spec(spec.arch(cfg).tiny(cfg), tr)
 
 
-@pytest.fixture(params=[("multitask_clip", "4task_b4"), ("ofasys", "4task_b2")],
-                ids=["multitask_clip", "ofasys"])
+@pytest.fixture(params=CONFIG_CELLS, ids=CONFIG_IDS)
 def tiny(request):
     return tiny_spec(*request.param)

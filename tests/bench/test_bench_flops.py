@@ -1,6 +1,6 @@
-"""bench/flops.py against what XLA and the jaxpr count for the program's own
-forward pass, at a tiny size on the CPU, for both configurations (towers, a
-merged decoder, contrastive joins)."""
+"""The architectures' FLOPs (``forward_flops``, and ``bench/flops.py``'s step)
+against what XLA and the jaxpr count for the program's own forward pass, at
+a tiny size on the CPU, for every configuration of BENCHMARK.json."""
 
 import math
 
@@ -8,6 +8,7 @@ import jax
 import pytest
 
 from bench import flops, spec as specmod
+from conftest import CONFIG_CELLS, CONFIG_IDS, tiny_spec
 
 
 def _dot_flops(jaxpr) -> float:
@@ -26,13 +27,9 @@ def _dot_flops(jaxpr) -> float:
     return total
 
 
-@pytest.fixture(scope="module", params=[("multitask_clip", "4task_b4"),
-                                        ("ofasys", "4task_b2")],
-                ids=["multitask_clip", "ofasys"])
+@pytest.fixture(scope="module", params=CONFIG_CELLS, ids=CONFIG_IDS)
 def forward(request):
     """The tiny spec and the program's whole-model forward pass on it."""
-    from conftest import tiny_spec
-
     from bench.program import build_model
 
     spec = tiny_spec(*request.param)
@@ -45,20 +42,21 @@ def forward(request):
 def test_forward_flops_match_the_programs_matmuls(forward):
     spec, fn, (weights, batch) = forward
     counted = _dot_flops(jax.make_jaxpr(fn)(weights, batch).jaxpr)
-    assert flops.forward_flops(spec) == pytest.approx(counted, rel=1e-12)
+    assert specmod.arch(spec).forward_flops(spec) == pytest.approx(
+        counted, rel=1e-12)
 
 
 def test_forward_flops_against_xla_cost_analysis(forward):
     spec, fn, (weights, batch) = forward
     xla = float(jax.jit(fn).lower(weights, batch).cost_analysis()["flops"])
-    ours = flops.forward_flops(spec)
+    ours = specmod.arch(spec).forward_flops(spec)
     # XLA also counts norms, softmax, RoPE and SiLU, which the model FLOPs
     # leave out: 3-4% at these widths (measured), never less than ours
     assert ours <= xla <= 1.15 * ours
 
 
 def test_step_is_three_forwards(tiny):
-    assert flops.step_flops(tiny) == 3 * flops.forward_flops(tiny)
+    assert flops.step_flops(tiny) == 3 * specmod.arch(tiny).forward_flops(tiny)
 
 
 def test_published_cells_per_step():

@@ -20,7 +20,7 @@ def test_reference_loss_matches_the_programs(tiny):
 
     weights = specmod.make_weights(tiny, SEED)
     batch = specmod.make_batches(tiny, SEED)[0]
-    ours = float(reference.loss(tiny, weights, batch))
+    ours = float(specmod.arch(tiny).loss(tiny, weights, batch, reference.dot))
     model = build_model(tiny, None)
     theirs = float(model.reference_loss(weights, batch))
     assert ours == pytest.approx(theirs, rel=1e-5)

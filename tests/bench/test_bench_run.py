@@ -4,6 +4,7 @@ finds by name is there and says what it is."""
 import json
 import os
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -15,6 +16,10 @@ from bench import harness, spec as specmod
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = specmod.load_benchmark(ROOT)
 WIDTHS = ("d_model", "n_heads", "d_ff", "vocab", "seq")
+#: keys that name a width: a hidden, intermediate, latent, state or
+#: projection size, a head size, an expansion factor, experts per token
+WIDTH_RE = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj|"
+                      r"head_size|expan|per_tok|d_model|d_ff")
 
 
 def _run(cwd, workload="multitask_clip.4task.1chip"):
@@ -49,12 +54,27 @@ def test_config_files_state_source_and_cuts(cfg):
     data = specmod.read_json(ROOT / cfg["file"])
     assert data["name"] == cfg["name"] and data["source"] == cfg["source"]
     assert sorted(data["reduced"]) == sorted(cfg["reduced"])
-    assert data["assumed"] and data["precision"]["params"] == "float32"
+    assert data["precision"]["matmul"]
+    # every key cut from the source states its published value beside it
+    for key in cfg["reduced"]:
+        assert f"published_{key}" in data, key
+        assert data[f"published_{key}"] != data[key], key
     assert not set(cfg["reduced"]) & set(WIDTHS)
+    assert not [k for k in cfg["reduced"] if WIDTH_RE.search(k)]
+    # its architecture's two modules are there
+    for part in ("reference", "program"):
+        assert (specmod.BENCH / "arch" / data["arch"] / f"{part}.py").is_file()
+    assert specmod.load_config(cfg["name"]) == data
+
+
+@pytest.mark.parametrize("name", ["multitask_clip", "ofasys"])
+def test_published_configs_cut_depth_by_three(name):
+    data = specmod.load_config(name)
+    assert data["assumed"] and data["precision"]["params"] == "float32"
+    assert data["reduced"] == ["layers"]
     # the cut is in depth alone, by one factor for every component
     ratios = {data["published_layers"][k] / v for k, v in data["layers"].items()}
     assert ratios == {3.0}
-    assert specmod.load_config(cfg["name"]) == data
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])

@@ -130,22 +130,25 @@ def test_reduce_ignores_program_spans():
         trace.reduce(_bench_events(), chips=2, window="bench_window")
 
 
-def _ctx(events, monkeypatch, window_s=0.1):
-    monkeypatch.setattr(spans, "load", lambda d: events)
-    return {"trace": {"window_s": window_s}, "steps": 2, "chips": 2}
+def _ctx(events):
+    return harness.trace_context(events, chips=2, steps=2)
 
 
 @pytest.mark.parametrize("name,want", READERS.items())
-def test_readers_read_the_run_s_trace(name, want, monkeypatch):
+def test_readers_read_the_run_s_trace(name, want):
     read = harness.load_reader(name)
-    ctx = _ctx(_bench_events(), monkeypatch)
+    ctx = _ctx(_bench_events())
     assert read(ctx) == pytest.approx(want)
-    assert ctx["spans"]["phases"]  # reduced once, kept for the other readers
+    # reduced once by the harness, from the events the trace's reduction read
+    assert ctx["spans"] == _reduce()
+    assert ctx["trace"] == trace.reduce(_bench_events(), chips=2,
+                                        window="bench_window")
     # a program without the spans: nothing to read
     bare = [e for e in _bench_events() if not e.name.startswith("spindle.")]
-    assert read(_ctx(bare, monkeypatch)) is None
-    # a trace of another run (its window differs) is not read
-    assert read(_ctx(_bench_events(), monkeypatch, window_s=0.2)) is None
+    assert _ctx(bare)["spans"] is None
+    assert read(_ctx(bare)) is None
+    # an untraced run: nothing to read
+    assert read({"steps": 2, "chips": 2}) is None
 
 
 def test_missing_window_or_chips_raise():

@@ -52,6 +52,18 @@ def test_busy_and_idle_share():
     assert ops["dot"] == pytest.approx(0.030)
 
 
+def test_every_op_time_kept():
+    """``op_s`` has every operation's seconds per chip, beyond the top ones
+    that ``device_ops`` lists."""
+    extra = [_op(0, f"op{i}.1", 70 + i, 70.5 + i) for i in range(15)]
+    red = trace.reduce(_events() + extra, chips=2, window="bench_window")
+    assert len(red["op_s"]) == 17 and len(red["device_ops"]) == trace.TOP
+    assert red["device_ops"] == trace._top(red["op_s"])
+    assert red["op_s"]["op3"] == pytest.approx(0.00025)
+    # chip 0: 15 + 10 + 10 + 15 x 0.5 ms; chip 1: 50 ms (clipped)
+    assert sum(red["op_s"].values()) == pytest.approx((42.5 + 50) / 2 * 1e-3)
+
+
 def test_gaps_attributed_to_host_spans():
     red = trace.reduce(_events(), chips=2, window="bench_window")
     gaps = dict(red["idle_gaps"])
