@@ -10,10 +10,13 @@ follows all of its steps (the sound readings); on the first
 ``--control-seeds`` seeds the control (the reference with its matrix
 products in three bfloat16 passes, one precision below the configuration's)
 over as many steps, against the same reference; and on the first
-``--fault-seeds`` seeds a run with the half-batch fault of
-``bench/faults.py`` planted. A state left unchanged reads 1 by construction
-and is not run. ``--out`` keeps every row with the raw per-step losses and per-leaf
-norms behind it. The benchmark's own runs never run this.
+``--fault-seeds`` seeds a run with each fault of ``bench/faults.py`` that
+the cell can have planted (the half batch; across chips also the exchange
+left out), through the first steps and the warm-up and a window of one
+step: a fault's readings need no measured window. A state left unchanged
+reads 1 by construction and is not run.
+``--out`` keeps every row with the raw per-step losses and per-leaf norms
+behind it. The benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -36,8 +39,8 @@ def readings(cell, seed0: int, n: int, n_control: int, n_fault: int,
     compiles = harness.CompileCounter()
     quiet = lambda s: None  # noqa: E731
 
-    def run(seed):
-        prog, _ = harness.drive(spec, seed, chips, seconds, compiles,
+    def run(seed, window):
+        prog, _ = harness.drive(spec, seed, chips, window, compiles,
                                 t_start=time.perf_counter(), log=quiet)
         return prog, len(prog["losses"])
 
@@ -45,10 +48,11 @@ def readings(cell, seed0: int, n: int, n_control: int, n_fault: int,
         return {k: [float(x) for x in r[k]]
                 for k in ("losses", "grad", "delta", "grad_raw") if k in r}
 
+    fault_kinds = [k for k in faults.kinds(chips) if k != "unchanged"]
     rows = []
     for i in range(n):
         seed = seed0 + i
-        prog, steps = run(seed)
+        prog, steps = run(seed, seconds)
         ref = harness.reference_readings(spec, seed, steps)
         row = {"seed": seed, "steps": steps,
                "program": compare.numbers(prog, ref)}
@@ -57,10 +61,10 @@ def readings(cell, seed0: int, n: int, n_control: int, n_fault: int,
             low = harness.reference_readings(spec, seed, steps, control=True)
             row["control"] = compare.numbers(low, ref)
             raws["control"] = raw(low)
-        if i < n_fault:
-            with faults.planted("half_batch"):
-                bad, bad_steps = run(seed)
-            row["half_batch"] = compare.numbers(
+        for kind in fault_kinds if i < n_fault else ():
+            with faults.planted(kind):
+                bad, bad_steps = run(seed, 0.0)
+            row[kind] = compare.numbers(
                 bad, harness.reference_readings(spec, seed, bad_steps))
         log(json.dumps(row))
         rows.append(dict(row, raw=raws))

@@ -7,14 +7,26 @@ duration of a ``with planted(kind):`` block:
 - ``unchanged``: a step that returns its parameters and optimizer state
   unchanged (the loss is still computed);
 - ``half_batch``: every task's batch cut to its first half, the means taken
-  over the rest.
+  over the rest;
+- ``no_exchange``: the exchange between chips left out: an activation that
+  ``WaveEngine._put`` moves onto another device group arrives there as
+  zeros. On one chip the engine moves nothing (``_put`` returns its input),
+  so the fault has no effect there: ``kinds(1)`` leaves it out.
 """
 
 from __future__ import annotations
 
 import contextlib
 
-KINDS = ("unchanged", "half_batch")
+import jax
+import jax.numpy as jnp
+
+KINDS = ("unchanged", "half_batch", "no_exchange")
+
+
+def kinds(chips: int):
+    """The faults that a cell on ``chips`` chips can have."""
+    return KINDS if chips > 1 else tuple(k for k in KINDS if k != "no_exchange")
 
 
 def _half(batches):
@@ -37,6 +49,14 @@ def planted(kind: str):
 
         def patched(self, params, batches, **kw):
             return orig(self, params, _half(batches), **kw)
+    elif kind == "no_exchange":
+        name, orig = "_put", WaveEngine._put
+
+        def patched(self, x, step):
+            y = orig(self, x, step)
+            if y.devices() == x.devices():
+                return y
+            return jax.device_put(jnp.zeros(y.shape, y.dtype), y.sharding)
     else:
         raise ValueError(f"unknown fault {kind!r}; choose from {KINDS}")
     setattr(WaveEngine, name, patched)

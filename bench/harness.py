@@ -187,7 +187,9 @@ def _drive(spec, seed, chips, seconds, compiles, t_start, spans, trace_dir,
     log(f"window: {win['steps']} steps in {win['window_s']:.3f} s, "
         f"{win['compiles']} compilations inside it")
     win["setup_s"] = setup_s
-    win["memory_peak_bytes"] = memory_peak(jax.devices()[:chips])
+    peaks = memory_peaks(jax.devices()[:chips])
+    log(f"memory: peak bytes in use per chip {peaks}")
+    win["memory_peak_bytes"] = max(peaks)
     prog["losses"] += win["losses"]
     prog["delta"] = change_readings(session, spec, seed)
     del session
@@ -195,9 +197,11 @@ def _drive(spec, seed, chips, seconds, compiles, t_start, spans, trace_dir,
     return prog, win
 
 
-def memory_peak(devices) -> int:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices]
-    return int(max(peaks))
+def memory_peaks(devices) -> List[int]:
+    """Each device's peak bytes in use (0 where the backend keeps none);
+    the result line reports the fullest chip's."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 def reference_readings(spec: Dict, seed: int, steps: int = CHECK_STEPS,
@@ -278,7 +282,8 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, traced: bool,
         result["breakdown"] = {"device_ops": red["device_ops"],
                                "idle_gaps": red["idle_gaps"]}
         log(f"trace: {red['n_device_ops']} device ops, busy {red['busy_s']!r} s "
-            f"of {red['window_s']!r} s per chip")
+            f"of {red['window_s']!r} s per chip (by chip "
+            f"{red['busy_s_per_chip']!r})")
         if prog_spans is not None:
             log(f"trace: program spans cover {prog_spans['covered_pct']!r}% of "
                 f"the idle time; idle by span {prog_spans['idle_by_span']!r}")

@@ -42,11 +42,21 @@ def test_sound_run_is_correct(cell, compiles, tmp_path):
     assert set(r["metrics"]) == {"step_s", "setup_s"}
 
 
-@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+@pytest.mark.parametrize("fault", faults.kinds(1))
 @pytest.mark.parametrize("cell", ONE_CHIP, ids=lambda c: c["name"])
 def test_planted_fault_is_not_correct(cell, fault, compiles, tmp_path):
     r = _run(cell, compiles, tmp_path, fault)
     assert not r["correct"], r["checks"]
+
+
+def test_no_exchange_has_no_effect_on_one_chip(compiles, tmp_path):
+    """One chip moves nothing between device groups, so the exchange left
+    out changes no number: a one-chip cell cannot have that fault."""
+    assert faults.kinds(1) == ("unchanged", "half_batch")
+    assert "no_exchange" in faults.kinds(4)
+    cell = ONE_CHIP[0]
+    assert _run(cell, compiles, tmp_path, "no_exchange")["checks"] == \
+        _run(cell, compiles, tmp_path)["checks"]
 
 
 @pytest.mark.parametrize("cell", ONE_CHIP, ids=lambda c: c["name"])
